@@ -37,6 +37,7 @@ from repro.calib import (FitConfig, SyntheticBackend, fit_profiles,
                          fit_report, holdout_mixes, perturb_profile,
                          validate)
 from repro.core.fleet import SLO
+from repro.launch.cache import enable_compile_cache
 from repro.core.profile import KernelProfile
 from repro.core.resources import TPU_V5E, TPU_V5P
 from repro.sim import Simulator, TraceConfig, generate_trace
@@ -205,6 +206,7 @@ def main(argv=None):
                          "--json overrides it")
     ap.add_argument("--json", type=str, default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     roundtrip = run_roundtrip()
     roundtrip_twin = run_roundtrip()

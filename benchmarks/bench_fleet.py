@@ -48,6 +48,7 @@ from repro.core import (TPU_V5E, TPU_V5P, BEST_EFFORT, SLO, FleetConfig,
                         FleetScheduler, KernelProfile, WorkloadProfile)
 from repro.core.resources import RESOURCE_AXES
 from repro.ft.inject import FakeClock, FaultInjector, arrive, kill, slow, storm
+from repro.launch.cache import enable_compile_cache
 
 TOL = 1e-9
 
@@ -377,6 +378,7 @@ def main(argv=None):
                     help="write a machine-readable result summary to this "
                          "path (implied as BENCH_fleet.json by --quick)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     dev = TPU_V5E
 
     print("== recovery (device kill) ==")

@@ -49,6 +49,7 @@ from repro.core import (LEGACY_SEARCH, TPU_V5E, ColocationScheduler,
                         KernelProfile, WorkloadProfile, estimate,
                         estimate_batch)
 from repro.core.resources import RESOURCE_AXES
+from repro.launch.cache import enable_compile_cache
 
 TOL = 1e-9
 
@@ -477,6 +478,7 @@ def main(argv=None):
     ap.add_argument("--churn-events", type=int, default=64,
                     help="arrive/leave events in the online-churn bench")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.quick:
         ns = args.n or [16, 64]

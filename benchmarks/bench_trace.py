@@ -36,6 +36,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 from repro.core import TPU_V5E, TPU_V5P
 from repro.sim import Simulator, TraceConfig, generate_trace
+from repro.launch.cache import enable_compile_cache
 
 # the fixed gate trace: 36 tenants (half SLO) on 12 devices (36 slots at
 # k=3), 240 virtual seconds of diurnal+burst traffic (~2.5k requests),
@@ -122,6 +123,7 @@ def main(argv=None):
                     help="write a machine-readable result summary to this "
                          "path (implied as BENCH_trace.json by --quick)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     report = run_once(GATE_TRACE)
     twin = run_once(GATE_TRACE)      # same seed, fresh everything

@@ -8,6 +8,9 @@ import traceback
 
 def main() -> None:
     from benchmarks import bench_roofline, paper_tables, tpu_native
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
 
     suites = (paper_tables.ALL + tpu_native.ALL + bench_roofline.ALL)
     print("name,us_per_call,derived")

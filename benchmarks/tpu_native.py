@@ -21,31 +21,30 @@ RESULTS = Path(__file__).resolve().parents[1] / "results" / "dryrun"
 
 
 def stressor_suite(repeats: int = 5) -> List[Row]:
-    """Wall-time of the Pallas microbenchmark suite (interpret mode on
-    CPU; on TPU the same calls compile to Mosaic).  Each kernel is timed
-    ``repeats`` times through the shared ``median_iqr_time`` timer
-    (median + IQR — one outlier dispatch no longer skews the row; the
-    calib Pallas backend measures with the same timer)."""
+    """Wall-time of the compiled Pallas stressors at full intensity
+    (``repro.calib.measure.stressor_kernel``) on the attached TPU, each
+    timed ``repeats`` times through the shared ``median_iqr_time`` timer
+    (median + IQR).  Without a TPU every row reads "not measured": the
+    Pallas interpreter's time says nothing about the kernel."""
     import jax
-    import jax.numpy as jnp
-    from repro.calib.measure import median_iqr_time
-    from repro.kernels import stressors
+    from repro.calib.measure import (StressorSpec, median_iqr_time,
+                                     stressor_kernel, stressor_operands)
 
+    dev = jax.devices()[0]
     rows = []
-    a = jax.random.normal(jax.random.PRNGKey(0), (2, 128, 128), jnp.float32)
-    b = jax.random.normal(jax.random.PRNGKey(1), (128, 128), jnp.float32) * .1
-    x = jax.random.normal(jax.random.PRNGKey(2), (512, 128), jnp.float32)
-
-    cases = [
-        ("stress_mxu_iters8", lambda: stressors.stress_mxu(a, b, iters=8, interpret=True)),
-        ("stress_vpu_ilp4", lambda: stressors.stress_vpu(x, iters=8, ilp=4, interpret=True)),
-        ("stress_hbm_copy", lambda: stressors.stress_hbm(x, interpret=True)),
-        ("stress_vmem_stride8", lambda: stressors.stress_vmem(x, iters=8, stride=8, interpret=True)),
-    ]
-    for name, fn in cases:
-        med_s, iqr_s = median_iqr_time(fn, repeats=repeats, warmup=1)
+    for axis in ("mxu", "vpu", "hbm", "smem"):
+        name = f"stress_{axis}"
+        if dev.platform != "tpu":
+            rows.append((name, float("nan"),
+                         f"not measured|no TPU ({dev.platform})"))
+            continue
+        kernel, _, operands = stressor_kernel(StressorSpec(axis, 1.0))
+        fn = jax.jit(kernel)
+        args = stressor_operands(operands)
+        med_s, iqr_s = median_iqr_time(lambda: fn(*args), repeats=repeats,
+                                       warmup=1)
         rows.append((name, med_s * 1e6,
-                     f"interpret-mode|median_of={repeats}"
+                     f"{dev.device_kind}|median_of={repeats}"
                      f"|iqr_us={iqr_s * 1e6:.1f}"))
     return rows
 
@@ -107,7 +106,8 @@ def serve_chunked_vs_serial() -> List[Row]:
     out = []
     for mode in ("serial", "interference_aware"):
         eng = Engine(cfg, ecfg=EngineConfig(max_slots=4, max_len=640,
-                                            prefill_chunk=64, mode=mode))
+                                            prefill_chunk=64, mode=mode),
+                     dev=TPU_V5E)
         eng.submit(list(range(1, 17)), max_new=24)       # short: decodes
         eng.run_until_done(max_steps=6)                  # warm decode
         eng.submit(list(range(1, 513)), max_new=4)       # long prompt

@@ -11,7 +11,7 @@ Run:  PYTHONPATH=src python examples/serve_colocation.py
 import numpy as np
 
 from repro.configs.registry import get_config, tiny_config
-from repro.core import Scenario, solve_scenarios
+from repro.core import TPU_V5E, Scenario, solve_scenarios
 from repro.serve import Engine, EngineConfig
 
 
@@ -19,7 +19,7 @@ def run(mode: str):
     cfg = tiny_config(get_config("qwen3-1.7b"))
     eng = Engine(cfg, ecfg=EngineConfig(max_slots=4, max_len=768,
                                         prefill_chunk=64, mode=mode,
-                                        tbt_slo_ms=1e-6))
+                                        tbt_slo_ms=1e-6), dev=TPU_V5E)
     # a decode-heavy workload...
     for _ in range(3):
         eng.submit(list(np.random.default_rng(0).integers(1, 99, 12)),
@@ -53,7 +53,7 @@ def show_chunk_pricing():
     """The engine's per-step decision, spelled out: one Scenario per
     chunk candidate (victim = decode batch, background = the chunk)."""
     cfg = tiny_config(get_config("qwen3-1.7b"))
-    eng = Engine(cfg, ecfg=EngineConfig())
+    eng = Engine(cfg, ecfg=EngineConfig(), dev=TPU_V5E)
     decode = eng._phase_profile("decode", 3)
     cands = [256, 128, 64, 32]
     chunks = [eng._phase_profile(f"prefill{c}", c) for c in cands]

@@ -13,9 +13,9 @@ and hand the resulting ``MeasurementSet`` to the fitter
     hidden truths make round-trip recovery a *checkable* property
     (``benchmarks/bench_calib.py``).
   * ``PallasBackend`` — runs the Pallas stressor kernels
-    (``repro.kernels.stressors``) concurrently with real victim
-    callables (interpret mode on CPU; the same calls compile to Mosaic
-    on TPU) and times the victim with the shared median+IQR repeat
+    (``repro.kernels.stressors``, compiled to Mosaic unless the caller
+    asks for interpret mode) concurrently with real victim callables
+    and times the victim with the shared median+IQR repeat
     timer (``median_iqr_time`` — also used by
     ``benchmarks/tpu_native.py``).
 
@@ -250,44 +250,68 @@ class SyntheticBackend:
 # Per-axis stressor kernels (repro.kernels.stressors).  Intensity scales
 # the work per dispatch; on real hardware the loop thread keeps the axis
 # busy for the victim's whole run.  Absolute intensity calibration
-# (λ of peak) needs TPU time — see ROADMAP item 4.
+# (λ of peak) needs TPU time — see ROADMAP A6.
 _STRESSOR_TILE = 128
+
+StressorKernel = Tuple[Callable, Callable, Tuple[Tuple[Tuple[int, ...], object], ...]]
+
+
+def stressor_kernel(spec: StressorSpec,
+                    interpret: bool = False) -> StressorKernel:
+    """The Pallas call that stresses ``spec.axis`` at ``spec.intensity``,
+    as ``(kernel, reference, operands)``: the kernel and its jnp oracle
+    (``repro.kernels.ref``) take the same arrays, whose ``(shape,
+    dtype)`` pairs are ``operands``.  The calibration sweep, the chip
+    smoke run and the TPU compile tests all build stressors here."""
+    from functools import partial
+
+    import jax.numpy as jnp
+
+    from repro.kernels import ref, stressors
+
+    lam = max(min(spec.intensity, 1.0), 0.05)
+    T = _STRESSOR_TILE
+    if spec.axis == "mxu":
+        iters = max(1, int(round(32 * lam)))
+        return (partial(stressors.stress_mxu, iters=iters,
+                        interpret=interpret),
+                partial(ref.ref_stress_mxu, iters=iters),
+                (((2, T, T), jnp.float32), ((T, T), jnp.float32)))
+    if spec.axis in ("vpu", "issue"):
+        iters = max(1, int(round(64 * lam)))
+        return (partial(stressors.stress_vpu, iters=iters, ilp=4,
+                        interpret=interpret),
+                partial(ref.ref_stress_vpu, iters=iters, ilp=4),
+                (((256, T), jnp.float32),))
+    if spec.axis in ("hbm", "l2", "ici"):
+        ws = spec.working_set or 8 * (1 << 20)
+        rows = max(8, int(ws / (4 * T)))
+        rows = 8 * max(1, round(rows / 8 * lam))
+        return (partial(stressors.stress_hbm, interpret=interpret),
+                ref.ref_stress_hbm,
+                (((rows, T), jnp.float32),))
+    if spec.axis == "smem":
+        iters = max(1, int(round(32 * lam)))
+        return (partial(stressors.stress_vmem, iters=iters, stride=8,
+                        interpret=interpret),
+                partial(ref.ref_stress_vmem, iters=iters, stride=8),
+                (((512, T), jnp.float32),))
+    raise ValueError(f"no Pallas stressor for axis {spec.axis!r}")
+
+
+def stressor_operands(operands, seed: int = 17) -> List[object]:
+    """Seeded standard-normal arrays for a stressor's ``operands``."""
+    import jax
+
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(operands))
+    return [jax.random.normal(k, shape, dtype)
+            for k, (shape, dtype) in zip(keys, operands)]
 
 
 def _stressor_call(spec: StressorSpec, interpret: bool) -> Callable[[], object]:
-    import jax
-    import jax.numpy as jnp
-
-    from repro.kernels import stressors
-
-    lam = max(min(spec.intensity, 1.0), 0.05)
-    key = jax.random.PRNGKey(17)
-    if spec.axis == "mxu":
-        a = jax.random.normal(key, (2, _STRESSOR_TILE, _STRESSOR_TILE),
-                              jnp.float32)
-        b = jax.random.normal(jax.random.PRNGKey(18),
-                              (_STRESSOR_TILE, _STRESSOR_TILE),
-                              jnp.float32) * 0.1
-        iters = max(1, int(round(32 * lam)))
-        return lambda: stressors.stress_mxu(a, b, iters=iters,
-                                            interpret=interpret)
-    if spec.axis in ("vpu", "issue"):
-        x = jax.random.normal(key, (256, _STRESSOR_TILE), jnp.float32)
-        iters = max(1, int(round(64 * lam)))
-        return lambda: stressors.stress_vpu(x, iters=iters, ilp=4,
-                                            interpret=interpret)
-    if spec.axis in ("hbm", "l2", "ici"):
-        ws = spec.working_set or 8 * (1 << 20)
-        rows = max(8, int(ws / (4 * _STRESSOR_TILE)))
-        rows = 8 * max(1, round(rows / 8 * lam))
-        x = jax.random.normal(key, (rows, _STRESSOR_TILE), jnp.float32)
-        return lambda: stressors.stress_hbm(x, interpret=interpret)
-    if spec.axis == "smem":
-        x = jax.random.normal(key, (512, _STRESSOR_TILE), jnp.float32)
-        iters = max(1, int(round(32 * lam)))
-        return lambda: stressors.stress_vmem(x, iters=iters, stride=8,
-                                             interpret=interpret)
-    raise ValueError(f"no Pallas stressor for axis {spec.axis!r}")
+    kernel, _, operands = stressor_kernel(spec, interpret)
+    args = stressor_operands(operands)
+    return lambda: kernel(*args)
 
 
 class PallasBackend:
@@ -296,23 +320,22 @@ class PallasBackend:
     loop on background threads.
 
     ``victims`` maps a name to a zero-arg callable issuing the victim
-    kernel (returning a jax value to block on).  On CPU the kernels run
-    in interpret mode and "colocation" is thread-level concurrency —
-    enough to smoke-test the pipeline end to end; on TPU the identical
-    calls lower to Mosaic and genuinely contend (the ROADMAP's
-    real-hardware item).  Wall-clock based, hence NOT deterministic —
+    kernel (returning a jax value to block on).  The stressors compile
+    to Mosaic; ``interpret=True`` (the CPU tests) runs them in the
+    Pallas interpreter instead, where "colocation" is thread-level
+    concurrency — enough to smoke-test the pipeline end to end.  Whether
+    threads of one process contend on one TPU core or run one after
+    another is ROADMAP A3.  Wall-clock based, hence NOT deterministic —
     CI gates use ``SyntheticBackend``.
     """
 
     def __init__(self, victims: Mapping[str, Callable[[], object]],
                  dev: DeviceModel, repeats: int = 5,
-                 interpret: Optional[bool] = None):
-        import jax
+                 interpret: bool = False):
         self._victims = dict(victims)
         self.device = dev
         self.repeats = int(repeats)
-        self.interpret = (jax.default_backend() != "tpu"
-                          if interpret is None else interpret)
+        self.interpret = interpret
         self._iso: Dict[str, float] = {}
 
     def isolated_time(self, victim: str) -> float:

@@ -3,8 +3,9 @@ quantification and colocation scheduling. See DESIGN.md §1-2."""
 from repro.core.backend import (SOLVER_BACKENDS, get_solver_backend,  # noqa: F401
                                 set_solver_backend, solver_backend,
                                 warmup_solver)
-from repro.core.resources import (DEVICES, H100, RTX3090, TPU_V5E,  # noqa: F401
-                                  TPU_V5P, DeviceModel)
+from repro.core.resources import (DEVICE_KINDS, DEVICES, H100,  # noqa: F401
+                                  RTX3090, TPU_V5E, TPU_V5P, DeviceModel,
+                                  device_model)
 from repro.core.profile import KernelProfile, ProfileMatrix, WorkloadProfile  # noqa: F401
 from repro.core.scenario import (CompiledScenarios, Scenario,  # noqa: F401
                                  compile_scenarios, group_victim_scenarios)

@@ -11,10 +11,12 @@ over the batch, so XLA fuses the whole pricing pipeline into a handful
 of kernels on whatever backend jax runs on (CPU today, TPU/GPU when
 present).
 
-Numerical contract: float64 everywhere (x64 is force-enabled at import;
-the parity gate is meaningless in f32), every floor/tolerance constant
+Numerical contract: float64 everywhere — x64 is enabled only around
+tracing and calling the solver (``jax.enable_x64`` in `solve_gathered`
+and `warmup`), never process-wide, so bf16/f32 model and kernel code in
+the same process keeps its dtypes; every floor/tolerance constant is
 imported from `repro.core.estimator` (never re-typed here), and results
-equal to the NumPy oracle at 1e-9 — enforced by
+equal the NumPy oracle at 1e-9 — enforced by
 ``tests/test_estimator_jax.py`` and the ``bench_planner`` solver gate in
 CI.  Selection happens in `repro.core.backend`; this module is only
 imported when the jax backend is requested.
@@ -24,33 +26,26 @@ bucketed up to powers of two (scenario padding rows are fully masked and
 solve to no-ops), so a scheduler churning through thousands of distinct
 batch sizes compiles O(log S_max x distinct K) programs, not O(events).
 
-The cache-share / thrash-cliff stage optionally runs as a Pallas TPU
-kernel (`repro.kernels.cache_share`) when jax is actually executing on a
-TPU; everywhere else the jnp fallback computes the identical expression
-(platform detection at dispatch, never inside the trace).
+The same XLA program runs on every platform: the cache-share stage is a
+row sum and an elementwise select over (S, K) that XLA fuses into the
+solve (on a TPU the f64 arithmetic is emulated by XLA).
 """
 from __future__ import annotations
 
-from functools import partial
 from typing import Tuple
 
 import numpy as np
 
 import jax
+import jax.numpy as jnp
+from jax import lax
 
-# the 1e-9 parity contract requires double precision — force it before
-# any array is created (harmless if already enabled via JAX_ENABLE_X64)
-jax.config.update("jax_enable_x64", True)
-
-import jax.numpy as jnp  # noqa: E402  (after x64 flip, by design)
-from jax import lax  # noqa: E402
-
-from repro.core.estimator import (CAP_REMAIN_FLOOR, DEMAND_EPS,  # noqa: E402
+from repro.core.estimator import (CAP_REMAIN_FLOOR, DEMAND_EPS,
                                   FRACTION_FLOOR, OVERSUB_RTOL, RATIO_FLOOR,
                                   SPEED_FLOOR, TIME_EPS, _INFLATION,
                                   _INFLATION_MAJORITY, _INFLATION_MIN_UTIL,
                                   _N_AXES, _SMEM, PER_SLOT_AXES)
-from repro.core.resources import AXIS_INDEX, RESOURCE_AXES, DeviceModel  # noqa: E402
+from repro.core.resources import AXIS_INDEX, RESOURCE_AXES, DeviceModel
 
 _HBM = AXIS_INDEX["hbm"]
 _L2 = AXIS_INDEX["l2"]
@@ -95,11 +90,10 @@ def _effective_demand(demand, ws, hit, cache_cap, share):
     return d.at[..., _L2].set(d_l2)
 
 
-def cache_share_ref(ws, present, cache_cap):
-    """The cache-share / thrash-cliff stage (jnp reference used on
-    non-TPU platforms and as the Pallas kernel's oracle): isolated
-    residency is proportional (min(1, C/ws)); colocated streaming
-    residency collapses once the combined working set exceeds capacity
+def _cache_share(ws, present, cache_cap):
+    """The cache-share / thrash-cliff stage: isolated residency is
+    proportional (min(1, C/ws)); colocated streaming residency
+    collapses once the combined working set exceeds capacity
     (paper Fig. 3's thrash cliff).  ws must already be exclusion-zeroed;
     shapes (S, K) / scalar -> (S, K)."""
     total_ws = ws.sum(-1, keepdims=True)
@@ -212,12 +206,12 @@ def _solve_one(demand, duration, ws, hit, slots, frac, present, excluded,
     return speeds, slowdowns, frozen, axis_load, feasible
 
 
-@partial(jax.jit, static_argnames=("use_pallas_share",))
+@jax.jit
 def _solve_padded(demand, duration, ws, hit, slots, frac, mask, cap_vec,
-                  cache_cap, n_slots, *, use_pallas_share: bool = False):
+                  cache_cap, n_slots):
     """The whole batch solve as one XLA program: exclusion zeroing, the
-    cache-share stage (Pallas on TPU), then the vmapped per-scenario
-    water-fill.  One trace per (padded S, K, use_pallas_share)."""
+    cache-share stage, then the vmapped per-scenario water-fill.  One
+    trace per padded (S, K); call it under ``jax.enable_x64(True)``."""
     global _trace_count
     _trace_count += 1
     excluded = mask & (frac <= FRACTION_FLOOR)
@@ -227,11 +221,7 @@ def _solve_padded(demand, duration, ws, hit, slots, frac, mask, cap_vec,
     ws = jnp.where(present, ws, 0.0)
     hit = jnp.where(present, hit, 0.0)
     slots = jnp.where(present, slots, 0.0)
-    if use_pallas_share:
-        from repro.kernels.cache_share import cache_share_pallas
-        share = cache_share_pallas(ws, present, cache_cap)
-    else:
-        share = cache_share_ref(ws, present, cache_cap)
+    share = _cache_share(ws, present, cache_cap)
     return jax.vmap(
         _solve_one,
         in_axes=(0, 0, 0, 0, 0, 0, 0, 0, 0, None, None, None))(
@@ -249,27 +239,17 @@ def warmup(dev: DeviceModel, ks=(2, 3),
     operands, so the traces are shared across device models.  Returns
     the number of new traces compiled (0 when every shape was warm)."""
     before = _trace_count
-    use_pallas = _use_pallas_share()
-    for K in ks:
-        for S in buckets:
-            shape = (int(S), int(K))
-            _solve_padded(
-                np.zeros(shape + (_N_AXES,)), np.zeros(shape),
-                np.zeros(shape), np.zeros(shape), np.zeros(shape),
-                np.ones(shape), np.zeros(shape, bool),
-                dev.capacity_vector(), dev.cache_capacity,
-                float(dev.n_slots), use_pallas_share=use_pallas)
+    with jax.enable_x64(True):
+        for K in ks:
+            for S in buckets:
+                shape = (int(S), int(K))
+                _solve_padded(
+                    np.zeros(shape + (_N_AXES,)), np.zeros(shape),
+                    np.zeros(shape), np.zeros(shape), np.zeros(shape),
+                    np.ones(shape), np.zeros(shape, bool),
+                    dev.capacity_vector(), dev.cache_capacity,
+                    float(dev.n_slots))
     return _trace_count - before
-
-
-def _use_pallas_share() -> bool:
-    """Platform detection for the Pallas cache-share kernel: only when
-    jax is actually executing on a TPU (the lax fallback is the same
-    expression everywhere else — CPU CI, GPU)."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:          # pragma: no cover - backend probing failed
-        return False
 
 
 def solve_gathered(mask, frac, demand, duration, ws, hit, slots,
@@ -289,10 +269,8 @@ def solve_gathered(mask, frac, demand, duration, ws, hit, slots,
         ws = np.pad(ws, z)
         hit = np.pad(hit, z)
         slots = np.pad(slots, z)
-    out = _solve_padded(demand, duration, ws, hit, slots, frac, mask,
-                        dev.capacity_vector(), dev.cache_capacity,
-                        float(dev.n_slots),
-                        use_pallas_share=_use_pallas_share())
-    speeds, slowdowns, frozen, axis_load, feasible = (
-        np.asarray(o)[:S] for o in out)
-    return speeds, slowdowns, frozen, axis_load, feasible
+    with jax.enable_x64(True):
+        out = _solve_padded(demand, duration, ws, hit, slots, frac, mask,
+                            dev.capacity_vector(), dev.cache_capacity,
+                            float(dev.n_slots))
+        return tuple(np.asarray(o)[:S] for o in out)

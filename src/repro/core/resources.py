@@ -134,6 +134,22 @@ RTX3090 = DeviceModel(
 DEVICES: Dict[str, DeviceModel] = {d.name: d for d in (TPU_V5E, TPU_V5P,
                                                        H100, RTX3090)}
 
+# ``jax.Device.device_kind`` of an attached chip -> the model that prices it
+DEVICE_KINDS: Dict[str, DeviceModel] = {
+    "TPU v5 lite": TPU_V5E,
+    "TPU v5": TPU_V5P,
+}
+
+
+def device_model(device_kind: str) -> DeviceModel:
+    """The ``DeviceModel`` of an attached chip, by its ``device_kind``.
+    A kind outside ``DEVICE_KINDS`` is an error, never a default."""
+    try:
+        return DEVICE_KINDS[device_kind]
+    except KeyError:
+        raise ValueError(f"no DeviceModel for device kind {device_kind!r}; "
+                         f"known kinds: {sorted(DEVICE_KINDS)}") from None
+
 
 def fp64_pipe(dev: DeviceModel) -> float:
     """FP64 pipeline (paper §4.4.3: half of FP32 rate on H100)."""

@@ -3,6 +3,8 @@
 Layout: q (B, KVH, G, D) — all query heads of one kv group together so the
 (G, bk) score tile feeds the MXU; k/v (B*KVH, T, D). The KV-length grid
 axis is sequential with m/l/acc scratch carry (flash-decode partials).
+The per-sequence valid lengths reach the kernel through scalar prefetch
+(the whole (BKV,) int32 vector lives in SMEM).
 """
 from __future__ import annotations
 
@@ -31,7 +33,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     k = k_ref[0]                                       # (bk, D)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    kv_len = len_ref[0]
+    kv_len = len_ref[pl.program_id(0)]
     k_pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(k_pos < kv_len, s, NEG_INF)
 
@@ -64,22 +66,24 @@ def flash_decode_bkgd(q, k, v, kv_len, *, block_k: int = 512,
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
     kernel = functools.partial(_kernel, scale=1.0 / math.sqrt(D),
                                block_k=block_k, n_kv_blocks=nk)
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(BKV, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, ik: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, G, D), lambda b, ik: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, ik: (b, ik, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, ik: (b, ik, 0)),
+            pl.BlockSpec((1, G, D), lambda b, ik, lens: (b, 0, 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, ik, lens: (b, ik, 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, ik, lens: (b, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, G, D), lambda b, ik: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((BKV, G, D), q.dtype),
+        out_specs=pl.BlockSpec((1, G, D), lambda b, ik, lens: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, D), jnp.float32),
         ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((BKV, G, D), q.dtype),
         interpret=interpret,
     )(kv_len.astype(jnp.int32), q, k, v)

@@ -1,9 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) every kernel runs in ``interpret=True`` mode — the
-kernel body executes in Python with identical semantics; on TPU the same
-call sites compile to Mosaic. ``repro.models.attention`` dispatches here
-when ``attn_impl == "pallas"``.
+Every wrapper compiles its kernel to Mosaic unless the caller passes
+``interpret=True`` (the CPU tests do: the kernel body then executes in
+Python with identical semantics). Nothing here picks interpret mode from
+the platform. ``repro.models.attention`` dispatches here when
+``attn_impl == "pallas"``.
 """
 from __future__ import annotations
 
@@ -19,14 +20,11 @@ from repro.kernels import ssm_scan as _ssm
 from repro.kernels import stressors as _st
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-@partial(jax.jit, static_argnames=("kind", "window", "softcap", "block_q", "block_k"))
+@partial(jax.jit, static_argnames=("kind", "window", "softcap", "block_q",
+                                   "block_k", "interpret"))
 def flash_attention(q, k, v, *, kind: str = "causal", window: int = 0,
                     softcap: float = 0.0, block_q: int = 128,
-                    block_k: int = 128):
+                    block_k: int = 128, interpret: bool = False):
     """Model-layout wrapper: q (B,S,H,D); k/v (B,T,KVH,D) -> (B,S,H,D).
     (softcap unsupported in the kernel; asserted off.)"""
     assert not softcap, "softcap not implemented in the Pallas kernel"
@@ -39,13 +37,14 @@ def flash_attention(q, k, v, *, kind: str = "causal", window: int = 0,
     qf = qf.reshape(B * H, S, D)
     o = _fa.flash_attention_bhsd(qf, kf, vf, kind=kind, window=window,
                                  block_q=block_q, block_k=block_k,
-                                 interpret=_interpret())
+                                 interpret=interpret)
     o = o.reshape(B, KVH, H // KVH, S, D).transpose(0, 3, 1, 2, 4)
     return o.reshape(B, S, H, D)
 
 
-@partial(jax.jit, static_argnames=("block_k",))
-def flash_decode(q, k, v, kv_len, *, block_k: int = 512):
+@partial(jax.jit, static_argnames=("block_k", "interpret"))
+def flash_decode(q, k, v, kv_len, *, block_k: int = 512,
+                 interpret: bool = False):
     """q (B,1,H,D); k/v (B,T,KVH,D); kv_len () or (B,) -> (B,1,H,D)."""
     B, _, H, D = q.shape
     T, KVH = k.shape[1], k.shape[2]
@@ -56,37 +55,21 @@ def flash_decode(q, k, v, kv_len, *, block_k: int = 512):
     kv_len = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (B,))
     lens = jnp.repeat(kv_len, KVH)
     o = _dec.flash_decode_bkgd(qf, kf, vf, lens, block_k=block_k,
-                               interpret=_interpret())
+                               interpret=interpret)
     return o.reshape(B, KVH, G, D).reshape(B, 1, H, D)
 
 
-@partial(jax.jit, static_argnames=("eps", "block_rows"))
-def rmsnorm(x, scale, eps: float = 1e-6, block_rows: int = 256):
+@partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))
+def rmsnorm(x, scale, eps: float = 1e-6, block_rows: int = 256,
+            interpret: bool = False):
     shape = x.shape
     out = _rms.rmsnorm_pallas(x.reshape(-1, shape[-1]), scale, eps=eps,
-                              block_rows=block_rows, interpret=_interpret())
+                              block_rows=block_rows, interpret=interpret)
     return out.reshape(shape)
 
 
-@partial(jax.jit, static_argnames=("chunk", "block_d"))
-def ssm_scan(x, dt, A, B, C, *, chunk: int = 64, block_d: int = 512):
+@partial(jax.jit, static_argnames=("chunk", "block_d", "interpret"))
+def ssm_scan(x, dt, A, B, C, *, chunk: int = 64, block_d: int = 512,
+             interpret: bool = False):
     return _ssm.ssm_scan_pallas(x, dt, A, B, C, chunk=chunk,
-                                block_d=block_d, interpret=_interpret())
-
-
-# stressors (used by the sensitivity harness + tests)
-def mxu_stressor(a, b, iters=64):
-    return _st.stress_mxu(a, b, iters=iters, interpret=_interpret())
-
-
-def vpu_stressor(x, iters=256, ilp=4):
-    return _st.stress_vpu(x, iters=iters, ilp=ilp, interpret=_interpret())
-
-
-def hbm_stressor(x, block_rows=1024):
-    return _st.stress_hbm(x, block_rows=block_rows, interpret=_interpret())
-
-
-def vmem_stressor(x, iters=64, stride=8):
-    return _st.stress_vmem(x, iters=iters, stride=stride,
-                           interpret=_interpret())
+                                block_d=block_d, interpret=interpret)

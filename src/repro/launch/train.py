@@ -17,6 +17,7 @@ import numpy as np
 from repro.configs.base import RunConfig
 from repro.configs.registry import get_config, tiny_config
 from repro.data import DataConfig, Prefetcher, SyntheticLM
+from repro.launch.cache import enable_compile_cache
 from repro.models import LOCAL_CTX, ParallelContext, build_model
 from repro.train.trainer import Trainer, TrainerConfig
 
@@ -38,6 +39,7 @@ def main(argv=None):
     ap.add_argument("--n-layers", type=int, default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.tiny:

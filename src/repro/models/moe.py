@@ -186,12 +186,11 @@ def moe_ffn(p: Params, cfg: ModelConfig, x: jnp.ndarray,
             aux = jax.lax.pmean(aux, a)
         return out.reshape(Bl, Sl, d), aux
 
-    from jax.experimental.shard_map import shard_map
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         shard_fn, mesh=ctx.mesh,
         in_specs=(batch_spec, P(), P(ax), P(ax), P(ax)),
         out_specs=(batch_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     out = out + _shared_ffn(p, cfg, x.reshape(-1, d)).reshape(B, S, d)
     return out, aux

@@ -50,8 +50,6 @@ def psum_int8(x, axis_name: str):
 
 def ring_allreduce_int8(mesh, axis: str):
     """shard_map wrapper: compressed all-reduce of a pytree over `axis`."""
-    from jax.experimental.shard_map import shard_map
-
     def fn(tree):
         def one(x):
             return psum_int8(x, axis)
@@ -60,7 +58,7 @@ def ring_allreduce_int8(mesh, axis: str):
 
     def call(tree):
         specs = jax.tree.map(lambda _: P(), tree)
-        return shard_map(fn, mesh=mesh, in_specs=(specs,), out_specs=specs,
-                         check_rep=False)(tree)
+        return jax.shard_map(fn, mesh=mesh, in_specs=(specs,),
+                             out_specs=specs, check_vma=False)(tree)
 
     return call

@@ -15,21 +15,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
-
-try:                                    # jax >= 0.6 public location
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_rep)
-except (ImportError, TypeError):
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-        return _shard_map_exp(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_rep)
 
 
 def pipeline_apply(mesh: Mesh, axis: str, stage_fn: Callable,
@@ -68,10 +55,13 @@ def pipeline_apply(mesh: Mesh, axis: str, stage_fn: Callable,
         return outs[None]   # (1, n_micro, ...) per stage
 
     spec_p = jax.tree.map(lambda _: P(axis), stage_params)
-    out = shard_map(inner, mesh,
-                    in_specs=(spec_p, P()), out_specs=P(axis))(
+    out = jax.shard_map(inner, mesh=mesh, in_specs=(spec_p, P()),
+                        out_specs=P(axis), check_vma=False)(
         stage_params, microbatches)
-    return out[-1]          # last stage's buffer holds the real outputs
+    # the last stage's buffer holds the real outputs; the stage axis is
+    # sharded, so replicate it before indexing (explicit-sharding meshes
+    # refuse a slice of a sharded dimension)
+    return jax.sharding.reshard(out, NamedSharding(mesh, P()))[-1]
 
 
 def split_stages(stacked_params, n_stages: int):

@@ -23,8 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.core import (TPU_V5E, DeviceModel, KernelProfile, Scenario,
-                        solve_scenarios)
+from repro.core import DeviceModel, KernelProfile, Scenario, solve_scenarios
 from repro.core.resources import RESOURCE_AXES
 from repro.models import LOCAL_CTX, ParallelContext, build_model
 from repro.models import transformer as tfm
@@ -54,9 +53,40 @@ class StepEvent:
     detail: dict = field(default_factory=dict)
 
 
+def engine_steps(model, ctx: ParallelContext = LOCAL_CTX):
+    """The engine's two jitted device programs, with the KV cache donated:
+    ``decode(params, tokens (B,1), cache, pos (B,))`` for the whole slot
+    batch and ``extend(params, tokens (1,C), cache, slot, pos0)`` for one
+    prefill chunk of one slot.  Both return ``(logits, cache)``."""
+    cfg = model.cfg
+
+    def decode(params, tokens, cache, pos_vec):
+        return model.decode_step(params, tokens, cache, pos_vec, ctx)
+
+    def extend(params, tokens, cache, slot, pos0):
+        x = embed(params["embed"], tokens, scale_by_dim=cfg.embed_scale)
+        ck = jax.lax.dynamic_slice_in_dim(cache["k"], slot, 1, axis=1)
+        cv = jax.lax.dynamic_slice_in_dim(cache["v"], slot, 1, axis=1)
+        x, ck, cv = tfm.uniform_stack_extend(
+            params["stack"], cfg, x, ck, cv, pos0, ctx=ctx)
+        cache = dict(cache,
+                     k=jax.lax.dynamic_update_slice_in_dim(
+                         cache["k"], ck, slot, axis=1),
+                     v=jax.lax.dynamic_update_slice_in_dim(
+                         cache["v"], cv, slot, axis=1))
+        x = rmsnorm(params["final_ln"], x[:, -1:], cfg.norm_eps)
+        return unembed(params["embed"], x), cache
+
+    return (jax.jit(decode, donate_argnums=(2,)),
+            jax.jit(extend, donate_argnums=(2,)))
+
+
 class Engine:
+    """``dev`` is the chip the engine prices its chunk decisions for
+    (``repro.core.device_model`` maps an attached TPU's kind to it)."""
+
     def __init__(self, cfg: ModelConfig, params=None, ecfg: EngineConfig = None,
-                 ctx: ParallelContext = LOCAL_CTX, dev: DeviceModel = TPU_V5E,
+                 ctx: ParallelContext = LOCAL_CTX, *, dev: DeviceModel,
                  key=None):
         assert cfg.family in ("dense", "moe") and cfg.attn.pattern == "global", \
             "engine supports uniform-attention decoders"
@@ -77,7 +107,7 @@ class Engine:
         self.metrics: Dict[int, dict] = {}
         self._next_id = 0
         self.degraded = False
-        self._build_steps()
+        self._decode, self._extend = engine_steps(self.model, ctx)
 
     def set_degraded(self, flag: bool, reason: str = "") -> None:
         """Fleet hook: the engine's device is oversubscribed (straggling,
@@ -90,32 +120,6 @@ class Engine:
             self.events.append(StepEvent(
                 "degraded" if flag else "recovered",
                 time.perf_counter(), {"reason": reason}))
-
-    # ------------------------------------------------------------- #
-    def _build_steps(self):
-        model, cfg, ctx = self.model, self.cfg, self.ctx
-
-        def decode(params, tokens, cache, pos_vec):
-            logits, cache = model.decode_step(params, tokens, cache, pos_vec,
-                                              ctx)
-            return logits, cache
-
-        def extend(params, tokens, cache, slot, pos0):
-            x = embed(params["embed"], tokens, scale_by_dim=cfg.embed_scale)
-            ck = jax.lax.dynamic_slice_in_dim(cache["k"], slot, 1, axis=1)
-            cv = jax.lax.dynamic_slice_in_dim(cache["v"], slot, 1, axis=1)
-            x, ck, cv = tfm.uniform_stack_extend(
-                params["stack"], cfg, x, ck, cv, pos0, ctx=ctx)
-            cache = dict(cache,
-                         k=jax.lax.dynamic_update_slice_in_dim(
-                             cache["k"], ck, slot, axis=1),
-                         v=jax.lax.dynamic_update_slice_in_dim(
-                             cache["v"], cv, slot, axis=1))
-            x = rmsnorm(params["final_ln"], x[:, -1:], cfg.norm_eps)
-            return unembed(params["embed"], x), cache
-
-        self._decode = jax.jit(decode, donate_argnums=(2,))
-        self._extend = jax.jit(extend, donate_argnums=(2,))
 
     # ------------------------------------------------------------- #
     def submit(self, prompt: List[int], max_new: int = 16) -> int:

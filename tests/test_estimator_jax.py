@@ -192,23 +192,28 @@ def test_jit_cache_two_shapes_two_traces():
         assert estimator_jax.trace_count() == t0 + 1
 
 
-def test_pallas_share_kernel_matches_ref():
-    """The Pallas cache-share kernel (interpret mode on CPU) computes
-    exactly the jnp fallback expression, including the cliff boundary."""
-    from repro.kernels.cache_share import cache_share_pallas
+def test_x64_stays_scoped_to_the_solver():
+    """Importing the jax solver and solving with it leave the process-wide
+    x64 flag alone, so f32/bf16 model and kernel code in the same process
+    keeps its dtypes (a global flip broke every Pallas kernel on a TPU)."""
+    import importlib
+
     import jax.numpy as jnp
-    rng = np.random.default_rng(9)
-    cap = DEV.cache_capacity
-    ws = rng.random((37, 3)) * 2.0 * cap
-    ws[rng.random((37, 3)) < 0.3] = 0.0
-    ws[0] = [cap / 2, cap / 2, 0.0]                  # total == cap exactly
-    present = rng.random((37, 3)) < 0.9
-    ws = np.where(present, ws, 0.0)
-    ref = estimator_jax.cache_share_ref(jnp.asarray(ws),
-                                        jnp.asarray(present), cap)
-    got = cache_share_pallas(jnp.asarray(ws), jnp.asarray(present), cap,
-                             interpret=True)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    prev = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", False)
+    try:
+        importlib.reload(estimator_jax)
+        assert jax.config.jax_enable_x64 is False
+        rng = np.random.default_rng(31)
+        pm = ProfileMatrix.from_profiles(pool(rng, 8))
+        idx = rng.integers(0, 8, (16, 3))
+        r_np, r_jx = both_backends(lambda: solve_batch(pm, idx, DEV))
+        assert_results_equal(r_np, r_jx)
+        assert r_jx.slowdowns.dtype == np.float64
+        assert jax.config.jax_enable_x64 is False
+        assert jnp.zeros(2).dtype == jnp.float32
+    finally:
+        jax.config.update("jax_enable_x64", prev)
 
 
 def test_backend_switch_and_env():

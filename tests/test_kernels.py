@@ -46,7 +46,7 @@ def test_flash_attention_model_layout():
     k = jax.random.normal(K(1), (B, S, KVH, D), jnp.float32)
     v = jax.random.normal(K(2), (B, S, KVH, D), jnp.float32)
     from repro.models.attention import reference_attention
-    out = ops.flash_attention(q, k, v, kind="causal")
+    out = ops.flash_attention(q, k, v, kind="causal", interpret=True)
     want = reference_attention(q, k, v, "causal")
     _allclose(out, want, rtol=2e-5, atol=2e-5)
 
@@ -73,7 +73,7 @@ def test_flash_decode_vs_model_decode_attention():
     k = jax.random.normal(K(1), (B, T, KVH, D), jnp.float32)
     v = jax.random.normal(K(2), (B, T, KVH, D), jnp.float32)
     lens = jnp.array([200, 64], jnp.int32)
-    out = ops.flash_decode(q, k, v, lens)
+    out = ops.flash_decode(q, k, v, lens, interpret=True)
     want = decode_attention(q, k, v, lens)
     _allclose(out, want, rtol=2e-5, atol=2e-5)
 

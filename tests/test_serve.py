@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.configs.registry import get_config, tiny_config
+from repro.core import TPU_V5E, TPU_V5P, device_model
 from repro.models import build_model
 from repro.serve import Engine, EngineConfig, SlotAllocator
 from repro.serve.kvcache import Sequence
@@ -27,7 +28,8 @@ def greedy_reference(cfg, params, prompt, max_new):
 @pytest.mark.parametrize("mode", ["serial", "interference_aware"])
 def test_engine_matches_full_forward(mode):
     eng = Engine(CFG, ecfg=EngineConfig(max_slots=2, max_len=96,
-                                        prefill_chunk=16, mode=mode))
+                                        prefill_chunk=16, mode=mode),
+                 dev=TPU_V5E)
     rng = np.random.default_rng(0)
     prompts = [rng.integers(1, CFG.vocab_size, size=n).tolist()
                for n in (9, 23)]
@@ -38,10 +40,25 @@ def test_engine_matches_full_forward(mode):
         assert metrics[i]["output"] == want, (mode, i)
 
 
+@pytest.mark.parametrize("kind, model", [("TPU v5 lite", TPU_V5E),
+                                         ("TPU v5", TPU_V5P)])
+def test_device_model_maps_attached_kind(kind, model):
+    assert device_model(kind) is model
+
+
+def test_device_model_refuses_unknown_kind_and_engine_needs_dev():
+    """The engine prices for the chip it runs on: an unknown
+    ``device_kind`` is an error, and there is no default model."""
+    with pytest.raises(ValueError, match="TPU v6 lite"):
+        device_model("TPU v6 lite")
+    with pytest.raises(TypeError, match="dev"):
+        Engine(CFG)
+
+
 def test_engine_continuous_batching_over_subscription():
     """More requests than slots: all must finish via slot recycling."""
     eng = Engine(CFG, ecfg=EngineConfig(max_slots=2, max_len=64,
-                                        prefill_chunk=16))
+                                        prefill_chunk=16), dev=TPU_V5E)
     rng = np.random.default_rng(1)
     ids = [eng.submit(rng.integers(1, 50, size=8).tolist(), max_new=3)
            for _ in range(5)]
@@ -57,7 +74,7 @@ def test_chunked_prefill_reduces_decode_gap():
     def interleavings(mode):
         eng = Engine(CFG, ecfg=EngineConfig(max_slots=2, max_len=320,
                                             prefill_chunk=32, mode=mode,
-                                            tbt_slo_ms=1e-6))
+                                            tbt_slo_ms=1e-6), dev=TPU_V5E)
         eng.submit([1, 2, 3, 4], max_new=40)     # decoder workload
         for _ in range(4):                        # let it start decoding
             eng.step()
@@ -89,7 +106,8 @@ def test_pick_chunk_prices_floor_chunk(monkeypatch):
 
     eng = Engine(CFG, ecfg=EngineConfig(max_slots=2, max_len=96,
                                         prefill_chunk=64,
-                                        tbt_slo_ms=1e-9))   # nothing passes
+                                        tbt_slo_ms=1e-9),
+                 dev=TPU_V5E)   # nothing passes
     priced_chunks = []
     real_solve = engine_mod.solve_scenarios
 
@@ -118,7 +136,7 @@ def test_pick_chunk_short_remainder_still_priced(monkeypatch):
     import repro.serve.engine as engine_mod
 
     eng = Engine(CFG, ecfg=EngineConfig(max_slots=2, max_len=96,
-                                        prefill_chunk=64))
+                                        prefill_chunk=64), dev=TPU_V5E)
     priced = []
     real_solve = engine_mod.solve_scenarios
 
@@ -203,7 +221,8 @@ def test_pick_chunk_degraded_mode_is_conservative():
     chunk boost is also disabled."""
     eng = Engine(CFG, ecfg=EngineConfig(max_slots=2, max_len=96,
                                         prefill_chunk=64,
-                                        tbt_slo_ms=1e9))   # everything passes
+                                        tbt_slo_ms=1e9),
+                 dev=TPU_V5E)   # everything passes
     seq = Sequence(0, prompt_len=80, max_new=1)
     assert eng._pick_chunk(seq, n_active_decodes=1) == 64
     assert eng._pick_chunk(seq, n_active_decodes=0) == 80
