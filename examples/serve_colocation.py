@@ -27,7 +27,8 @@ def run(mode: str):
     for _ in range(5):
         eng.step()
     # ...interrupted by a LONG prompt (the paper's sleep-kernel analogue)
-    eng.submit(list(np.random.default_rng(1).integers(1, 99, 512)), max_new=4)
+    long_id = eng.submit(list(np.random.default_rng(1).integers(1, 99, 512)),
+                         max_new=4)
     eng.run_until_done()
 
     # structural HOL metric: how many decode steps ran BETWEEN the long
@@ -36,15 +37,12 @@ def run(mode: str):
     # container is dominated by XLA compiles, so the schedule itself is
     # the meaningful observable.
     kinds = [e.kind for e in eng.events]
-    big_chunks = [i for i, e in enumerate(eng.events)
-                  if e.kind == "prefill_chunk" and e.detail.get("chunk", 0) >= 16
-                  and i > 8]
-    interleaved = (kinds[big_chunks[0]:big_chunks[-1]].count("decode")
-                   if len(big_chunks) > 1 else 0)
-    chunks = [e.detail["chunk"] for e in eng.events
-              if e.kind == "prefill_chunk"]
+    long_chunks = [i for i, e in enumerate(eng.events)
+                   if e.kind == "prefill_chunk" and e.detail["seq"] == long_id]
+    interleaved = (kinds[long_chunks[0]:long_chunks[-1]].count("decode")
+                   if len(long_chunks) > 1 else 0)
     print(f"mode={mode:20s} long prompt split into "
-          f"{len(chunks) - 3} chunk(s); decode steps interleaved during "
+          f"{len(long_chunks)} chunk(s); decode steps interleaved during "
           f"its prefill: {interleaved}")
     return interleaved
 

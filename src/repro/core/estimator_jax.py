@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs
 from repro.core.estimator import (CAP_REMAIN_FLOOR, DEMAND_EPS,
                                   FRACTION_FLOOR, OVERSUB_RTOL, RATIO_FLOOR,
                                   SPEED_FLOOR, TIME_EPS, _INFLATION,
@@ -259,18 +260,21 @@ def solve_gathered(mask, frac, demand, duration, ws, hit, slots,
     (masked rows solve to no-ops), runs the jitted program, and returns
     NumPy (speeds, slowdowns, bottleneck, axis_load, feasible_slots)."""
     S, K = mask.shape
-    pad = _bucket(S) - S
-    if pad:
-        z = ((0, pad), (0, 0))
-        mask = np.pad(mask, z)
-        frac = np.pad(frac, z, constant_values=1.0)
-        demand = np.pad(demand, z + ((0, 0),))
-        duration = np.pad(duration, z)
-        ws = np.pad(ws, z)
-        hit = np.pad(hit, z)
-        slots = np.pad(slots, z)
-    with jax.enable_x64(True):
-        out = _solve_padded(demand, duration, ws, hit, slots, frac, mask,
-                            dev.capacity_vector(), dev.cache_capacity,
-                            float(dev.n_slots))
-        return tuple(np.asarray(o)[:S] for o in out)
+    with obs.span("price.solve"):
+        pad = _bucket(S) - S
+        if pad:
+            z = ((0, pad), (0, 0))
+            mask = np.pad(mask, z)
+            frac = np.pad(frac, z, constant_values=1.0)
+            demand = np.pad(demand, z + ((0, 0),))
+            duration = np.pad(duration, z)
+            ws = np.pad(ws, z)
+            hit = np.pad(hit, z)
+            slots = np.pad(slots, z)
+        with jax.enable_x64(True):
+            out = _solve_padded(demand, duration, ws, hit, slots, frac, mask,
+                                dev.capacity_vector(), dev.cache_capacity,
+                                float(dev.n_slots))
+            jax.block_until_ready(out)
+            with obs.span("price.fetch"):
+                return tuple(np.asarray(o)[:S] for o in out)

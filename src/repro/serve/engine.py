@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.core import DeviceModel, KernelProfile, Scenario, solve_scenarios
 from repro.core.resources import RESOURCE_AXES
@@ -47,8 +48,8 @@ class EngineConfig:
 
 @dataclass
 class StepEvent:
-    kind: str                  # "decode" | "prefill_chunk" | "admit" |
-                               # "finish" | "degraded" | "recovered"
+    kind: str                  # "decode" | "prefill_chunk" |
+                               # "degraded" | "recovered"
     t: float
     detail: dict = field(default_factory=dict)
 
@@ -157,96 +158,122 @@ class Engine:
         and always take the minimum-predicted-TBT candidate — the
         interference budget belongs to the migrated/SLO work, not to
         prefill throughput."""
-        remaining = seq.prompt_len - seq.pos
-        if self.ecfg.mode == "serial":
-            return remaining
-        if self.ecfg.mode == "fixed_chunk":
-            return min(self.ecfg.prefill_chunk, remaining)
-        if n_active_decodes == 0:
-            boost = 1 if self.degraded else 4
-            return min(self.ecfg.prefill_chunk * boost, remaining)
-        chunk = min(self.ecfg.prefill_chunk, remaining)
-        cands = []
-        while chunk > _MIN_CHUNK:
-            cands.append(chunk)
-            chunk //= 2
-        cands.append(max(chunk, _MIN_CHUNK))   # the floor chunk is priced too
-        decode = self._phase_profile("decode", max(n_active_decodes, 1))
-        chunks = [self._phase_profile(f"prefill{c}", c) for c in cands]
-        br = solve_scenarios([Scenario((decode,), (ch,)) for ch in chunks],
-                             self.dev)
-        tbt_iso = decode.isolated_time(self.dev)
-        t_chunk = np.asarray([ch.isolated_time(self.dev) for ch in chunks])
-        tbt_pred = tbt_iso * br.slowdowns[:, 0] + t_chunk
-        if self.degraded:
+        with obs.span("serve.price"):
+            remaining = seq.prompt_len - seq.pos
+            if self.ecfg.mode == "serial":
+                return remaining
+            if self.ecfg.mode == "fixed_chunk":
+                return min(self.ecfg.prefill_chunk, remaining)
+            if n_active_decodes == 0:
+                boost = 1 if self.degraded else 4
+                return min(self.ecfg.prefill_chunk * boost, remaining)
+            chunk = min(self.ecfg.prefill_chunk, remaining)
+            cands = []
+            while chunk > _MIN_CHUNK:
+                cands.append(chunk)
+                chunk //= 2
+            cands.append(max(chunk, _MIN_CHUNK))   # the floor is priced too
+            decode = self._phase_profile("decode", max(n_active_decodes, 1))
+            chunks = [self._phase_profile(f"prefill{c}", c) for c in cands]
+            br = solve_scenarios(
+                [Scenario((decode,), (ch,)) for ch in chunks], self.dev)
+            tbt_iso = decode.isolated_time(self.dev)
+            t_chunk = np.asarray([ch.isolated_time(self.dev)
+                                  for ch in chunks])
+            tbt_pred = tbt_iso * br.slowdowns[:, 0] + t_chunk
+            if self.degraded:
+                return cands[int(np.argmin(tbt_pred))]
+            ok = tbt_pred <= max(self.ecfg.tbt_slo_ms / 1e3, tbt_iso * 1.5)
+            passing = np.flatnonzero(ok)
+            if passing.size:
+                return cands[passing[0]]
+            # nothing keeps TBT within SLO: degrade to the estimator-backed
+            # minimum — the priced candidate with the lowest predicted TBT
+            # (the old fallback returned an unpriced cands[-1] // 2)
             return cands[int(np.argmin(tbt_pred))]
-        ok = tbt_pred <= max(self.ecfg.tbt_slo_ms / 1e3, tbt_iso * 1.5)
-        passing = np.flatnonzero(ok)
-        if passing.size:
-            return cands[passing[0]]
-        # nothing keeps TBT within SLO: degrade to the estimator-backed
-        # minimum — the priced candidate with the lowest predicted TBT
-        # (the old fallback returned an unpriced cands[-1] // 2)
-        return cands[int(np.argmin(tbt_pred))]
 
     # ----------------------------- loop --------------------------- #
     def step(self) -> bool:
         """One scheduler iteration. Returns False when idle."""
         now = time.perf_counter
-        # 1) admit waiting sequences into free slots
-        while self.waiting and self.alloc.can_admit(self.waiting[0]):
-            seq = self.waiting.pop(0)
-            self.alloc.admit(seq)
-            self.events.append(StepEvent("admit", now(),
-                                         {"seq": seq.seq_id, "slot": seq.slot}))
-        active = list(self.alloc.active.values())
-        prefilling = [s for s in active if s.pos < s.prompt_len]
-        decoding = [s for s in active if s.pos >= s.prompt_len and not s.done]
-        if not active:
-            return False
+        with obs.span("serve.step"):
+            # 1) admit waiting sequences into free slots
+            with obs.span("serve.admit"):
+                while self.waiting and self.alloc.can_admit(self.waiting[0]):
+                    seq = self.waiting.pop(0)
+                    self.alloc.admit(seq)
+                    seq.admit_time = now()
+            active = list(self.alloc.active.values())
+            prefilling = [s for s in active if s.pos < s.prompt_len]
+            decoding = [s for s in active
+                        if s.pos >= s.prompt_len and not s.done]
+            if not active:
+                return False
 
-        # 2) one prefill chunk for the oldest prefilling sequence
-        if prefilling:
-            seq = prefilling[0]
-            chunk = self._pick_chunk(seq, len(decoding))
-            tok = np.asarray(seq.tokens[seq.pos:seq.pos + chunk],
-                             np.int32)[None, :]
-            logits, self.cache = self._extend(
-                self.params, jnp.asarray(tok), self.cache,
-                seq.slot, seq.pos)
-            self.events.append(StepEvent(
-                "prefill_chunk", now(),
-                {"seq": seq.seq_id, "chunk": int(tok.shape[1]),
-                 "colocated_decodes": len(decoding)}))
-            seq.pos += tok.shape[1]
-            if seq.pos >= seq.prompt_len:
-                nxt = self._sample(np.asarray(logits)[0, -1])
-                seq.tokens.append(nxt)
-                seq.first_token_time = now()
-                seq.pos += 1
+            # 2) one prefill chunk for the oldest prefilling sequence
+            if prefilling:
+                seq = prefilling[0]
+                chunk = self._pick_chunk(seq, len(decoding))
+                tok = np.asarray(seq.tokens[seq.pos:seq.pos + chunk],
+                                 np.int32)[None, :]
+                if seq.pos == 0:
+                    seq.first_chunk_time = now()
+                with obs.span("serve.extend"):
+                    logits, self.cache = self._extend(
+                        self.params, jnp.asarray(tok), self.cache,
+                        seq.slot, seq.pos)
+                self.events.append(StepEvent(
+                    "prefill_chunk", now(),
+                    {"seq": seq.seq_id, "chunk": int(tok.shape[1]),
+                     "colocated_decodes": len(decoding)}))
+                seq.pos += tok.shape[1]
+                if seq.pos >= seq.prompt_len:
+                    with obs.span("serve.first_token"):
+                        nxt = self._sample(np.asarray(logits)[0, -1])
+                        seq.tokens.append(nxt)
+                        seq.first_token_time = now()
+                        seq.pos += 1
+                    self._record_prefill(seq)
 
-        # 3) one decode step for the whole decode batch
-        if decoding:
-            B = self.ecfg.max_slots
-            tokens = np.zeros((B, 1), np.int32)
-            pos = np.full((B,), self.ecfg.max_len, np.int32)   # trash slot
-            for s in decoding:
-                tokens[s.slot, 0] = s.tokens[-1]
-                pos[s.slot] = s.pos - 1   # position of the token being fed
-            logits, self.cache = self._decode(
-                self.params, jnp.asarray(tokens), self.cache,
-                jnp.asarray(pos))
-            logits = np.asarray(logits)
-            self.events.append(StepEvent("decode", now(),
-                                         {"batch": len(decoding)}))
-            for s in decoding:
-                nxt = self._sample(logits[s.slot, 0])
-                s.tokens.append(nxt)
-                s.pos += 1
-                if s.pos - s.prompt_len >= s.max_new:
-                    s.done = True
-                    self._finish(s)
-        return True
+            # 3) one decode step for the whole decode batch
+            if decoding:
+                B = self.ecfg.max_slots
+                with obs.span("serve.decode.inputs"):
+                    tokens = np.zeros((B, 1), np.int32)
+                    pos = np.full((B,), self.ecfg.max_len, np.int32)  # trash
+                    for s in decoding:
+                        tokens[s.slot, 0] = s.tokens[-1]
+                        pos[s.slot] = s.pos - 1  # position of the token fed
+                    tokens, pos = jnp.asarray(tokens), jnp.asarray(pos)
+                with obs.span("serve.decode"):
+                    logits, self.cache = self._decode(
+                        self.params, tokens, self.cache, pos)
+                with obs.span("serve.decode.wait"):
+                    logits.block_until_ready()
+                with obs.span("serve.decode.fetch"):
+                    logits = np.asarray(logits)
+                self.events.append(StepEvent("decode", now(),
+                                             {"batch": len(decoding)}))
+                with obs.span("serve.sample"):
+                    for s in decoding:
+                        nxt = self._sample(logits[s.slot, 0])
+                        s.tokens.append(nxt)
+                        s.pos += 1
+                        if s.pos - s.prompt_len >= s.max_new:
+                            s.done = True
+                            self._finish(s)
+            return True
+
+    @staticmethod
+    def _record_prefill(seq: Sequence) -> None:
+        """A request's way to its first token, under its ``seq`` id:
+        submit -> admit -> first chunk dispatched -> first token."""
+        sid = seq.seq_id
+        obs.record("serve.queue", seq.arrival, seq.admit_time, seq=sid)
+        obs.record("serve.prefill_wait", seq.admit_time,
+                   seq.first_chunk_time, seq=sid)
+        obs.record("serve.prefill", seq.first_chunk_time,
+                   seq.first_token_time, seq=sid)
 
     def _sample(self, logits: np.ndarray) -> int:
         if self.ecfg.temperature <= 0:
@@ -263,8 +290,6 @@ class Engine:
             "output": seq.tokens[seq.prompt_len:],
         }
         self.alloc.release(seq.seq_id)
-        self.events.append(StepEvent("finish", time.perf_counter(),
-                                     {"seq": seq.seq_id}))
 
     def run_until_done(self, max_steps: int = 10_000) -> Dict[int, dict]:
         for _ in range(max_steps):

@@ -24,6 +24,8 @@ class Sequence:
     done: bool = False
     tokens: List[int] = field(default_factory=list)
     arrival: float = 0.0
+    admit_time: Optional[float] = None
+    first_chunk_time: Optional[float] = None     # its first chunk dispatched
     first_token_time: Optional[float] = None
 
 
