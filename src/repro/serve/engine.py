@@ -109,6 +109,9 @@ class Engine:
         self._next_id = 0
         self.degraded = False
         self._decode, self._extend = engine_steps(self.model, ctx)
+        # the greedy pick (argmax, the lowest index on ties as np.argmax),
+        # jitted per engine: its cache holds this engine's logits shapes
+        self._greedy = jax.jit(lambda logits: jnp.argmax(logits, axis=-1))
 
     def set_degraded(self, flag: bool, reason: str = "") -> None:
         """Fleet hook: the engine's device is oversubscribed (straggling,
@@ -194,7 +197,14 @@ class Engine:
 
     # ----------------------------- loop --------------------------- #
     def step(self) -> bool:
-        """One scheduler iteration. Returns False when idle."""
+        """One scheduler iteration. Returns False when idle.
+
+        Admits waiting sequences, runs at most one prefill chunk and one
+        decode step over the whole static slot batch, and picks each new
+        token with ``_sample`` over that program's device logits: at
+        temperature <= 0 only the ids cross to the host, and the ids are
+        read by ``slot``, so the pick sees two shapes whatever the number
+        of decoding slots."""
         now = time.perf_counter
         with obs.span("serve.step"):
             # 1) admit waiting sequences into free slots
@@ -229,8 +239,7 @@ class Engine:
                 seq.pos += tok.shape[1]
                 if seq.pos >= seq.prompt_len:
                     with obs.span("serve.first_token"):
-                        nxt = self._sample(np.asarray(logits)[0, -1])
-                        seq.tokens.append(nxt)
+                        seq.tokens.append(int(self._sample(logits)[0, -1]))
                         seq.first_token_time = now()
                         seq.pos += 1
                     self._record_prefill(seq)
@@ -251,13 +260,15 @@ class Engine:
                 with obs.span("serve.decode.wait"):
                     logits.block_until_ready()
                 with obs.span("serve.decode.fetch"):
-                    logits = np.asarray(logits)
-                self.events.append(StepEvent("decode", now(),
-                                             {"batch": len(decoding)}))
+                    ids = self._sample(logits)
+                self.events.append(StepEvent(
+                    "decode", now(),
+                    {"batch": len(decoding),
+                     "pick": "device" if self.ecfg.temperature <= 0
+                     else "host"}))
                 with obs.span("serve.sample"):
                     for s in decoding:
-                        nxt = self._sample(logits[s.slot, 0])
-                        s.tokens.append(nxt)
+                        s.tokens.append(int(ids[s.slot, 0]))
                         s.pos += 1
                         if s.pos - s.prompt_len >= s.max_new:
                             s.done = True
@@ -275,12 +286,23 @@ class Engine:
         obs.record("serve.prefill", seq.first_chunk_time,
                    seq.first_token_time, seq=sid)
 
-    def _sample(self, logits: np.ndarray) -> int:
+    def _sample(self, logits: jax.Array) -> np.ndarray:
+        """The batch picker: device logits ``[..., V]`` to host token ids
+        ``[...]``, one per row.  At temperature <= 0 the argmax runs on
+        the device and only the int32 ids are copied to the host.  Above
+        it the logits are copied and each row is sampled on the host from
+        its softmax at that temperature, with a generator seeded from
+        ``seed`` for every row."""
         if self.ecfg.temperature <= 0:
-            return int(np.argmax(logits))
-        p = np.exp((logits - logits.max()) / self.ecfg.temperature)
-        p /= p.sum()
-        return int(np.random.default_rng(self.ecfg.seed).choice(len(p), p=p))
+            return np.asarray(self._greedy(logits))
+        logits = np.asarray(logits)
+        rows = logits.reshape(-1, logits.shape[-1])
+        ids = np.empty(len(rows), np.int32)
+        for i, row in enumerate(rows):
+            p = np.exp((row - row.max()) / self.ecfg.temperature)
+            p /= p.sum()
+            ids[i] = np.random.default_rng(self.ecfg.seed).choice(len(p), p=p)
+        return ids.reshape(logits.shape[:-1])
 
     def _finish(self, seq: Sequence):
         self.metrics[seq.seq_id] = {

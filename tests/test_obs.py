@@ -173,3 +173,27 @@ def test_profiler_alone_traces_spans_and_keeps_none(tmp_path):
     assert {"serve.clock", "serve.step", "serve.decode.fetch"} <= set(events)
     assert obs.drain() == []
     assert obs.span("serve.step") is obs.span("serve.admit")
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.9],
+                         ids=["device_pick", "host_pick"])
+def test_fetch_and_sample_spans_at_either_pick(temperature):
+    """Each decode step opens one ``serve.decode.fetch`` (the pick and
+    what it copies to the host) and one ``serve.sample`` (the host loop
+    over the decoding slots), whichever side picks."""
+    obs.enable()
+    eng = Engine(CFG, ecfg=EngineConfig(max_slots=2, max_len=96,
+                                        prefill_chunk=16,
+                                        temperature=temperature),
+                 dev=TPU_V5E)
+    rng = np.random.default_rng(4)
+    for n in (9, 21):
+        eng.submit(rng.integers(1, 50, size=n).tolist(), max_new=5)
+    eng.run_until_done()
+    got = obs.drain()
+    decodes = sum(e.kind == "decode" for e in eng.events)
+    assert decodes
+    for name in ("serve.decode.fetch", "serve.sample"):
+        mine = [s for s in got if s.name == name]
+        assert len(mine) == decodes, name
+        assert all(s.parent == "serve.step" for s in mine), name

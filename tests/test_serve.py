@@ -236,3 +236,69 @@ def test_pick_chunk_degraded_mode_is_conservative():
     eng.set_degraded(False)            # idempotent: no duplicate event
     assert eng._pick_chunk(seq, n_active_decodes=1) == 64
     assert [e.kind for e in eng.events[-2:]] == ["degraded", "recovered"]
+
+
+def _host_draw(row, temperature, seed):
+    """One row drawn on the host, slot by slot as the engine always drew:
+    softmax at ``temperature``, a generator seeded with ``seed``."""
+    p = np.exp((row - row.max()) / temperature)
+    p /= p.sum()
+    return int(np.random.default_rng(seed).choice(len(p), p=p))
+
+
+@pytest.mark.parametrize("batch", [5, 1], ids=["decode_B1V", "extend_11V"])
+def test_device_pick_is_host_argmax_lowest_index_on_ties(batch):
+    """The greedy pick on the device reads what ``np.argmax`` reads over
+    the host copy of the same logits, ties included."""
+    eng = Engine(CFG, ecfg=EngineConfig(max_slots=batch, max_len=32),
+                 dev=TPU_V5E)
+    V = CFG.vocab_size
+    logits = np.random.default_rng(7).standard_normal(
+        (batch, 1, V)).astype(np.float32)
+    top = logits.max() + 1.0
+    logits[0, 0, [3, 11, V - 1]] = top           # a three-way tie
+    if batch > 1:
+        logits[1, 0, [V - 2, V - 1]] = top       # a tie at the far end
+        logits[2, 0, :] = 0.5                    # every index ties
+    ids = eng._sample(jnp.asarray(logits))
+    assert ids.shape == (batch, 1)
+    np.testing.assert_array_equal(ids, np.argmax(logits, axis=-1))
+    assert ids[0, 0] == 3
+    if batch > 1:
+        assert ids[1, 0] == V - 2 and ids[2, 0] == 0
+
+
+@pytest.mark.parametrize("batch", [4, 1], ids=["decode_B1V", "extend_11V"])
+def test_sampled_pick_keeps_the_host_formula(batch):
+    """Above temperature 0 every row is drawn on the host exactly as the
+    per-slot formula drew it, so a fixed seed serves the same tokens."""
+    ecfg = EngineConfig(max_slots=batch, max_len=32, temperature=0.7,
+                        seed=11)
+    eng = Engine(CFG, ecfg=ecfg, dev=TPU_V5E)
+    logits = 3.0 * np.random.default_rng(5).standard_normal(
+        (batch, 1, CFG.vocab_size)).astype(np.float32)
+    ids = eng._sample(jnp.asarray(logits))
+    want = [[_host_draw(logits[b, 0], ecfg.temperature, ecfg.seed)]
+            for b in range(batch)]
+    assert ids.tolist() == want
+    assert eng._greedy._cache_size() == 0        # the device never picked
+
+
+@pytest.mark.parametrize("temperature, pick", [(0.0, "device"),
+                                               (0.8, "host")])
+def test_decode_events_say_where_the_token_was_picked(temperature, pick):
+    """Every decode step records its pick; a greedy run over a changing
+    number of decoding slots compiles the pick for two shapes at most."""
+    eng = Engine(CFG, ecfg=EngineConfig(max_slots=3, max_len=64,
+                                        prefill_chunk=16,
+                                        temperature=temperature),
+                 dev=TPU_V5E)
+    rng = np.random.default_rng(2)
+    want = {eng.submit(rng.integers(1, 50, size=n).tolist(), max_new=new):
+            new for n, new in ((5, 9), (12, 3), (30, 6), (7, 2))}
+    m = eng.run_until_done()
+    assert {i: v["new_tokens"] for i, v in m.items()} == want
+    decodes = [e.detail for e in eng.events if e.kind == "decode"]
+    assert len({d["batch"] for d in decodes}) > 1
+    assert decodes and all(d["pick"] == pick for d in decodes)
+    assert eng._greedy._cache_size() == (2 if pick == "device" else 0)
